@@ -48,8 +48,7 @@ def _legacy_benches():
     # imported lazily: aes_function pulls in jax, which --list and the
     # scenario suites never need
     from benchmarks import (aes_function, coldstart, fig5_latency, fig6_load,
-                            model_endpoints, multitenant, polling_efficiency,
-                            roofline_table)
+                            multitenant, polling_efficiency)
     return [
         ("fig5_latency", fig5_latency),
         ("fig6_load", fig6_load),
@@ -57,8 +56,6 @@ def _legacy_benches():
         ("polling_efficiency", polling_efficiency),
         ("multitenant", multitenant),
         ("aes_function", aes_function),
-        ("model_endpoints", model_endpoints),
-        ("roofline_table", roofline_table),
     ]
 
 

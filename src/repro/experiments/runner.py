@@ -1180,6 +1180,10 @@ class ExperimentRunner:
 
     # -- execution --------------------------------------------------------
     def _execute(self, items: List[Tuple[Scenario, str, float, bool]]):
+        """Runs the cells, in a fork ``Pool`` when ``workers`` > 1.  The
+        pool is for simulation-only cells: the simulator imports no JAX,
+        so no worker touches a device, and no chip path calls this (a chip
+        belongs to one process at a time)."""
         if self.workers and self.workers > 1 and len(items) > 1:
             with multiprocessing.Pool(min(self.workers, len(items))) as pool:
                 return pool.map(_run_backend, items)

@@ -11,8 +11,6 @@ paper-claim deltas always computed from the scenario's claims pair.
 """
 from __future__ import annotations
 
-import json
-import os
 from typing import Dict, List
 
 from repro.core.latency import AES_600B_WORK_US
@@ -34,23 +32,9 @@ from repro.experiments.scenario import (ArrivalSpec, AutoscalerSpec,
 _COARSE_SEARCH = SearchSpec(rate0_frac=0.15, rel_tol=0.20, max_probes=6,
                             smoke_rel_tol=0.35, smoke_max_probes=2)
 
-_DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                           "experiments", "dryrun")
-
-# analytic decode-step service times (µs) used when no dry-run roofline
-# record exists; overridden by repro.launch.dryrun artifacts when present
-_ENDPOINT_FALLBACK_US = {"qwen3-1.7b": 450.0, "mixtral-8x7b": 1800.0}
-
-
-def _roofline_step_us(arch: str, shape: str = "decode_32k") -> float:
-    path = os.path.join(_DRYRUN_DIR, f"{arch}__{shape}__pod16x16.json")
-    if os.path.exists(path):
-        with open(path) as f:
-            rec = json.load(f)
-        roof = rec.get("roofline")
-        if roof:
-            return float(roof["step_time_s"]) * 1e6
-    return _ENDPOINT_FALLBACK_US[arch]
+# analytic decode-step service times (µs) of the model endpoints; the
+# scenario reads nothing outside the tree, so every checkout runs it alike
+_ENDPOINT_STEP_US = {"qwen3-1.7b": 450.0, "mixtral-8x7b": 1800.0}
 
 
 def _trace_burst_train(n_bursts: int = 6, burst_n: int = 120,
@@ -290,12 +274,12 @@ def build_scenarios() -> Dict[str, Scenario]:
             name="model-endpoint",
             description="Model decode steps as junctiond functions: how "
                         "much of an ms-scale endpoint budget the FaaS "
-                        "runtime costs (reuses serving/ dry-run rooflines)",
+                        "runtime costs (analytic decode-step times)",
             mode="closed",
             functions=tuple(
-                FunctionProfile(arch, work_us=_roofline_step_us(arch),
+                FunctionProfile(arch, work_us=_ENDPOINT_STEP_US[arch],
                                 payload_bytes=2048, response_bytes=2048)
-                for arch in sorted(_ENDPOINT_FALLBACK_US)),
+                for arch in sorted(_ENDPOINT_STEP_US)),
             n_requests=50, seeds=(5, 6), tags=("serving", "endpoint")),
     ]
     return {sc.name: sc for sc in scenarios}

@@ -1,12 +1,14 @@
-"""Jit'd public wrappers for every Pallas kernel, with an ``xla`` fallback
-(the oracle path) selectable via backend= — the model code calls these so
-the same model runs on CPU (xla / interpret) and TPU (pallas).
+"""Jit'd public wrappers for every Pallas kernel.  Every call names its
+backend: ``pallas`` (compiled, the chip path), ``pallas_interpret`` (the
+Pallas interpreter, CPU tests) or ``xla`` (the pure-jnp oracle).  Nothing
+is chosen for the caller, so a run never lands on another path than the
+one it asked for.
 """
 from __future__ import annotations
 
-import os
-from typing import Optional
+import functools
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
@@ -18,24 +20,13 @@ from repro.kernels.moe_gmm import moe_gmm as _gmm_pallas
 from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv_pallas
 
 
-def default_backend() -> str:
-    """'pallas' on TPU, 'xla' elsewhere; override with REPRO_KERNEL_BACKEND
-    ('pallas_interpret' validates kernels on CPU)."""
-    env = os.environ.get("REPRO_KERNEL_BACKEND")
-    if env:
-        return env
-    import jax
-    return "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+def _resolve(backend: str) -> str:
+    if backend not in ("pallas", "pallas_interpret", "xla"):
+        raise ValueError(f"unknown kernel backend {backend!r}")
+    return backend
 
 
-def _resolve(backend: Optional[str]):
-    b = backend or default_backend()
-    if b not in ("pallas", "pallas_interpret", "xla"):
-        raise ValueError(f"unknown kernel backend {b!r}")
-    return b
-
-
-def flash_attention(q, k, v, *, causal=True, window=None, backend=None):
+def flash_attention(q, k, v, *, causal=True, window=None, backend: str):
     b = _resolve(backend)
     if b == "xla":
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
@@ -43,14 +34,14 @@ def flash_attention(q, k, v, *, causal=True, window=None, backend=None):
                          interpret=(b == "pallas_interpret"))
 
 
-def decode_attention(q, k, v, valid, *, backend=None):
+def decode_attention(q, k, v, valid, *, backend: str):
     b = _resolve(backend)
     if b == "xla":
         return ref.decode_attention_ref(q, k, v, valid)
     return _decode_pallas(q, k, v, valid, interpret=(b == "pallas_interpret"))
 
 
-def mamba_scan(dt, dtx, Bm, Cm, A, *, backend=None):
+def mamba_scan(dt, dtx, Bm, Cm, A, *, backend: str):
     b = _resolve(backend)
     if b == "xla":
         return ref.mamba_scan_ref(dt, dtx, Bm, Cm, A)
@@ -58,22 +49,24 @@ def mamba_scan(dt, dtx, Bm, Cm, A, *, backend=None):
                          interpret=(b == "pallas_interpret"))
 
 
-def rwkv6_scan(r, k, v, w, u, *, backend=None):
+def rwkv6_scan(r, k, v, w, u, *, backend: str):
     b = _resolve(backend)
     if b == "xla":
         return ref.rwkv6_scan_ref(r, k, v, w, u)
     return _rwkv_pallas(r, k, v, w, u, interpret=(b == "pallas_interpret"))
 
 
-def moe_gmm(x, w, *, backend=None):
+def moe_gmm(x, w, *, backend: str):
     b = _resolve(backend)
     if b == "xla":
         return ref.moe_gmm_ref(x, w)
     return _gmm_pallas(x, w, interpret=(b == "pallas_interpret"))
 
 
+@functools.partial(jax.jit, static_argnames=("backend",))
 def aes_ctr(plaintext: jnp.ndarray, key_bytes: jnp.ndarray, *, nonce: int = 0,
-            backend=None):
+            backend: str):
+    """One dispatch per call: the key schedule is compiled in with the body."""
     b = _resolve(backend)
     if b == "xla":
         return ref.aes_ctr_ref(plaintext, key_bytes, nonce)

@@ -23,6 +23,8 @@ def main():
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.config import get_arch, reduced
     from repro.train import AdamWConfig, DataConfig, SyntheticLM, train
 

@@ -1,9 +1,8 @@
 """GQA attention with RoPE, optional qk-norm and sliding-window, plus a
 single-token decode path against a (ring-buffered) KV cache.
 
-Reference path is pure jnp (the oracle / dry-run path, lowered by XLA).
-On real TPU hardware the Pallas kernels in :mod:`repro.kernels` are
-selected via ``backend="pallas"``.
+Pure jnp, lowered by XLA on every device, the TPU included: no Pallas
+kernel of :mod:`repro.kernels` is on this path.
 """
 from __future__ import annotations
 

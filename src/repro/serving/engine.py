@@ -1,9 +1,10 @@
 """Serving engine: compiled prefill/decode steps + generation loop.
 
 This is the "model endpoint" a junctiond function deploys.  It measures
-its own per-step wall time so the FaaS layer can use measured service
-times (CPU, reduced models) or roofline-derived analytic ones (full
-models on the production mesh).
+its own per-step wall time, each step timed to ``block_until_ready``, so
+the FaaS layer can use the measured decode step as the function body's
+service time.  The first call of each step compiles it: callers that
+want steady-state times warm up first and ``reset_timers()``.
 """
 from __future__ import annotations
 
@@ -31,7 +32,8 @@ class ServingEngine:
         self.batcher = ContinuousBatcher(self.kv, batch_slots)
         self.caches = None
         self._rng = jax.random.PRNGKey(seed + 1)
-        self.step_times_s: List[float] = []
+        self.prefill_s: List[float] = []
+        self.decode_s: List[float] = []
 
         @jax.jit
         def _prefill(params, tokens):
@@ -59,7 +61,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         logits, caches = self._prefill(self.params, tokens)
         logits.block_until_ready()
-        self.step_times_s.append(time.perf_counter() - t0)
+        self.prefill_s.append(time.perf_counter() - t0)
         pos = plen
         self._rng, k = jax.random.split(self._rng)
         next_tok = sample(logits, k, temperature)
@@ -70,7 +72,7 @@ class ServingEngine:
             logits, caches = self._decode(self.params, next_tok[:, None],
                                           jnp.int32(pos), caches)
             logits.block_until_ready()
-            self.step_times_s.append(time.perf_counter() - t0)
+            self.decode_s.append(time.perf_counter() - t0)
             self._rng, k = jax.random.split(self._rng)
             next_tok = sample(logits, k, temperature)
             pos += 1
@@ -81,7 +83,25 @@ class ServingEngine:
         return [r.generated for r in reqs]
 
     # ------------------------------------------------------------------
+    def replay_logits(self, tokens: List[List[int]], prompt_len: int) -> jnp.ndarray:
+        """Teacher-forced run of the same compiled prefill and decode steps
+        over ``tokens``: prefill the first ``prompt_len``, then decode the
+        rest one at a time.  Returns (B, n - prompt_len, V) logits; row j
+        predicts token ``prompt_len + j``, as ``generate`` saw them."""
+        toks = jnp.asarray(tokens, jnp.int32)
+        logits, caches = self._prefill(self.params, toks[:, :prompt_len])
+        out = [logits[:, -1]]
+        for pos in range(prompt_len, toks.shape[1] - 1):
+            logits, caches = self._decode(self.params, toks[:, pos:pos + 1],
+                                          jnp.int32(pos), caches)
+            out.append(logits[:, -1])
+        return jnp.stack(out, axis=1)
+
+    def reset_timers(self) -> None:
+        self.prefill_s.clear()
+        self.decode_s.clear()
+
     def mean_decode_step_us(self) -> float:
-        if len(self.step_times_s) <= 1:
+        if not self.decode_s:
             return float("nan")
-        return 1e6 * sum(self.step_times_s[1:]) / len(self.step_times_s[1:])
+        return 1e6 * sum(self.decode_s) / len(self.decode_s)

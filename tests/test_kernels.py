@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.aes_ctr import aes_ctr
+from repro.kernels.aes_ctr import _sub_byte, _xtime, aes_ctr
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba_scan
@@ -115,13 +115,23 @@ def test_aes_fips197_vector():
     assert list(map(int, ct)) == expect
 
 
-@pytest.mark.parametrize("n_blocks", [1, 38, 40])   # 600B = 38 blocks
-def test_aes_ctr_kernel(n_blocks):
+def test_aes_table_free_sbox_and_xtime():
+    """The kernel's select-tree S-box and arithmetic xtime equal the
+    256-entry tables on every byte."""
+    x = jnp.arange(256, dtype=jnp.int32)
+    np.testing.assert_array_equal(_sub_byte(x), ref.SBOX)
+    np.testing.assert_array_equal(_xtime(x), ref.XTIME)
+
+
+# 600B = 38 blocks; 2100 blocks take three grid steps of 1024 and a
+# counter that crosses a byte boundary above the nonce
+@pytest.mark.parametrize("n_blocks,nonce", [(1, 0), (38, 0), (40, 7), (2100, 65000)])
+def test_aes_ctr_kernel(n_blocks, nonce):
     key_bytes = jnp.arange(16, dtype=jnp.int32)
     pt = jax.random.randint(KEY, (n_blocks, 16), 0, 256)
     rk = ref.aes_key_expand(key_bytes)
-    ct = aes_ctr(pt, rk, block_n=16, interpret=True)
-    np.testing.assert_array_equal(ct, ref.aes_ctr_ref(pt, key_bytes))
+    ct = aes_ctr(pt, rk, nonce=nonce, interpret=True)
+    np.testing.assert_array_equal(ct, ref.aes_ctr_ref(pt, key_bytes, nonce))
 
 
 def test_aes_ctr_roundtrip():
