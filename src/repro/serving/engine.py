@@ -5,6 +5,19 @@ its own per-step wall time, each step timed to ``block_until_ready``, so
 the FaaS layer can use the measured decode step as the function body's
 service time.  The first call of each step compiles it: callers that
 want steady-state times warm up first and ``reset_timers()``.
+
+``generate`` also opens ``repro.serve.*`` spans (``TraceAnnotation``) in
+its host code, which a profiler session puts on its trace's clock:
+
+- ``repro.serve.generate``: one call (one batch);
+- ``repro.serve.prefill`` and ``repro.serve.decode``: the intervals that
+  ``prefill_s`` and ``decode_s`` time, dispatch to ``block_until_ready``;
+- ``repro.serve.sample``: the key split and ``sample`` after each step;
+- ``repro.serve.readback``: one pass that reads the running slots' tokens
+  to the host and records them, after each step;
+- ``repro.serve.host_read``: one device-to-host read in that pass.
+
+No span is opened inside a jitted function.
 """
 from __future__ import annotations
 
@@ -13,6 +26,7 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.config import ArchConfig
 from repro.models import transformer as T
@@ -53,34 +67,46 @@ class ServingEngine:
                  temperature: float = 0.0) -> List[List[int]]:
         """Batched greedy/temperature generation (all prompts same length
         for the compiled shape; the batcher handles slot lifecycle)."""
-        reqs = [self.batcher.submit(p, max_new_tokens) for p in prompts]
-        self.batcher.admit_ready()
-        plen = len(prompts[0])
-        assert all(len(p) == plen for p in prompts), "batch requires equal prompt lengths"
-        tokens = jnp.asarray(prompts, jnp.int32)
-        t0 = time.perf_counter()
-        logits, caches = self._prefill(self.params, tokens)
-        logits.block_until_ready()
-        self.prefill_s.append(time.perf_counter() - t0)
-        pos = plen
-        self._rng, k = jax.random.split(self._rng)
-        next_tok = sample(logits, k, temperature)
-        for slot, r in list(self.batcher.running.items()):
-            self.batcher.record_token(slot, int(next_tok[slot]))
-        while any(not r.done for r in reqs) and pos < self.max_seq_len - 1:
-            t0 = time.perf_counter()
-            logits, caches = self._decode(self.params, next_tok[:, None],
-                                          jnp.int32(pos), caches)
-            logits.block_until_ready()
-            self.decode_s.append(time.perf_counter() - t0)
+        with TraceAnnotation("repro.serve.generate"):
+            reqs = [self.batcher.submit(p, max_new_tokens) for p in prompts]
+            self.batcher.admit_ready()
+            plen = len(prompts[0])
+            assert all(len(p) == plen for p in prompts), "batch requires equal prompt lengths"
+            tokens = jnp.asarray(prompts, jnp.int32)
+            with TraceAnnotation("repro.serve.prefill"):
+                t0 = time.perf_counter()
+                logits, caches = self._prefill(self.params, tokens)
+                logits.block_until_ready()
+                self.prefill_s.append(time.perf_counter() - t0)
+            pos = plen
+            next_tok = self._sample(logits, temperature)
+            self._read_back(next_tok)
+            while any(not r.done for r in reqs) and pos < self.max_seq_len - 1:
+                with TraceAnnotation("repro.serve.decode"):
+                    t0 = time.perf_counter()
+                    logits, caches = self._decode(self.params, next_tok[:, None],
+                                                  jnp.int32(pos), caches)
+                    logits.block_until_ready()
+                    self.decode_s.append(time.perf_counter() - t0)
+                next_tok = self._sample(logits, temperature)
+                pos += 1
+                self._read_back(next_tok)
+                if not self.batcher.running:
+                    break
+            return [r.generated for r in reqs]
+
+    def _sample(self, logits, temperature: float) -> jnp.ndarray:
+        with TraceAnnotation("repro.serve.sample"):
             self._rng, k = jax.random.split(self._rng)
-            next_tok = sample(logits, k, temperature)
-            pos += 1
+            return sample(logits, k, temperature)
+
+    def _read_back(self, next_tok) -> None:
+        """Record each running slot's token, read to the host one slot at a time."""
+        with TraceAnnotation("repro.serve.readback"):
             for slot in list(self.batcher.running):
-                self.batcher.record_token(slot, int(next_tok[slot]))
-            if not self.batcher.running:
-                break
-        return [r.generated for r in reqs]
+                with TraceAnnotation("repro.serve.host_read"):
+                    token = int(next_tok[slot])
+                self.batcher.record_token(slot, token)
 
     # ------------------------------------------------------------------
     def replay_logits(self, tokens: List[List[int]], prompt_len: int) -> jnp.ndarray:
