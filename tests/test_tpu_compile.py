@@ -12,6 +12,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -53,9 +54,26 @@ def _fits(compiled) -> int:
 
 @pytest.mark.parametrize("n_blocks", [38, 4096])    # 600 B; 64 KiB
 def test_aes_kernel_compiles_for_v5e(one_chip, n_blocks):
-    pt = jax.ShapeDtypeStruct((n_blocks, 16), jnp.int32, sharding=one_chip)
+    """The program an invocation from host bytes runs: the payload and its
+    counter in one (N + 1, 16) buffer, and the key."""
     key = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
-    compiled = ops.aes_ctr.lower(pt, key, backend="pallas").compile()
+    args = _on(one_chip, ops.aes_ctr_args(np.zeros((n_blocks, 16), np.int32), key, 38 * 40800))
+    lowered = ops.aes_ctr_program.lower(*args, backend="pallas")
+    params = [a.shape for a in jax.tree.leaves(lowered.args_info)]
+    assert params == [(n_blocks + 1, 16), (16,)]
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_aes_kernel_device_input_compiles_for_v5e(one_chip):
+    """The program for bytes already on the device: the counter is traced."""
+    pt = jax.ShapeDtypeStruct((38, 16), jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    nonce = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    lowered = ops.aes_ctr_program.lower(*ops.aes_ctr_args(pt, key, nonce), backend="pallas")
+    assert len(jax.tree.leaves(lowered.args_info)) == 3
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
 
